@@ -13,4 +13,5 @@ CONFIG = ModelConfig(
     d_ff=18944,
     vocab_size=152_064,
     qkv_bias=True,
+    rope_theta=1_000_000.0,
 )

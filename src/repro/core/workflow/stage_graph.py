@@ -54,6 +54,10 @@ from repro.core.workflow.weight_sync import (BroadcastWeightChannel,
                                              StaggeredUpdateGroup,
                                              WeightReceiver, WeightSender)
 
+# how long the step driver waits for a step's rows before it fails the
+# run as stalled
+STEP_ROWS_TIMEOUT_S = 60.0
+
 
 @dataclass
 class WorkflowConfig:
@@ -94,6 +98,14 @@ class WorkflowConfig:
     @property
     def samples_per_step(self) -> int:
         return self.prompts_per_step * self.group_size
+
+    def fetch_rows(self, got: int) -> int:
+        """Rows the step driver fetches next with ``got`` of the step in
+        hand: the rest of the step in baseline mode, else at most one
+        train micro-batch."""
+        left = self.samples_per_step - got
+        return left if self.mode == "baseline" \
+            else min(self.train_micro_batch, left)
 
 
 @dataclass
@@ -391,7 +403,8 @@ class StageRunner:
                 metrics=self.registry)
         self._train_step = resume_step    # next step the driver runs
         self._feed_start = resume_step    # dataset/prompt-feed cursor
-        self._trainer_epoch = 0           # bumped per warm restart (fence)
+        self._warm_prompts: Dict[int, List[Any]] = {}  # step -> prompts
+        self._trainer_epoch = 0          # bumped per warm restart (fence)
         self._trainer_restarts = 0
         self._last_snapshot_step = resume_step if resume else -1
         self._acked_uids: set = set()     # consumed watermark (dup guard)
@@ -830,17 +843,19 @@ class StageRunner:
         for step in range(self._train_step, cfg.num_steps):
             got = 0
             while got < cfg.samples_per_step and not self._stop.is_set():
-                want = (cfg.samples_per_step - got
-                        if cfg.mode == "baseline"
-                        else min(cfg.train_micro_batch,
-                                 cfg.samples_per_step - got))
+                want = cfg.fetch_rows(got)
                 t0 = time.monotonic()
                 batch = self.tq.get(spec.name, want, consumer=name,
-                                    timeout=60.0, lease=use_lease)
+                                    timeout=STEP_ROWS_TIMEOUT_S,
+                                    lease=use_lease)
                 self.log.record(name, "wait", t0, time.monotonic())
                 if batch is None:
-                    self._stop.set()
-                    return
+                    if self._stop.is_set():
+                        return      # another stage failed; run() raises
+                    raise TimeoutError(
+                        f"step {step}: no rows after "
+                        f"{STEP_ROWS_TIMEOUT_S:.0f} s ({got} of "
+                        f"{cfg.samples_per_step} this step)")
                 lease = batch.pop("lease", None)
                 idxs = batch.pop("indices", None) or []
                 if use_lease and idxs:
@@ -1041,7 +1056,9 @@ class StageRunner:
                     self._step_done.wait(0.05)
             if self._stop.is_set():
                 break
-            prompts = self.prompt_stream(step)
+            prompts = self._warm_prompts.pop(step, None)
+            if prompts is None:
+                prompts = self.prompt_stream(step)
             idxs = self.tq.next_indices(len(prompts))
             self.tq.put_batch(idxs, self._source_col, prompts,
                               token_lens=[len(p) if hasattr(p, "__len__")
@@ -1079,7 +1096,29 @@ class StageRunner:
             if handle is not None and self._supervisor is not None:
                 self._supervisor.retire(handle.rid)
 
+    def _warm_up(self) -> None:
+        """Compile generation and the step driver's update at every shape
+        this run gives them, before any stage thread starts: a first
+        compile at full width then never reads as a hung replica or a
+        stalled step. Engines without a ``warm_up`` compile as they go."""
+        cfg = self.cfg
+        gen = self.engines.get(self.gen_stage.engine)
+        if hasattr(gen, "warm_up"):
+            self._warm_prompts = {s: self.prompt_stream(s) for s in
+                                  range(self._feed_start, cfg.num_steps)}
+            gen.warm_up(self._driver_engine.params,
+                        [p for ps in self._warm_prompts.values() for p in ps],
+                        self.gen_stage.batch_size or cfg.rollout_batch)
+        if hasattr(self._driver_engine, "warm_up"):
+            rows, got = set(), 0
+            while got < cfg.samples_per_step:
+                n = cfg.fetch_rows(got)
+                rows.add(n)
+                got += n
+            self._driver_engine.warm_up(sorted(rows))
+
     def run(self) -> WorkflowResult:
+        self._warm_up()
         sampler = None
         if self.cfg.metrics_jsonl:
             sampler = MetricsSampler(self.registry, self.cfg.metrics_jsonl,
@@ -1109,11 +1148,14 @@ class StageRunner:
                         args=(self._transform_worker, spec, w),
                         kwargs=dict(stage=spec.name, worker=w),
                         daemon=True))
+            drainers: Dict[threading.Thread, str] = {}
             for spec in self.stream_train_stages:
-                self._threads.append(threading.Thread(
+                t = threading.Thread(
                     target=self._guard,
                     args=(self._stream_train_worker, spec),
-                    kwargs=dict(stage=spec.name, worker=0), daemon=True))
+                    kwargs=dict(stage=spec.name, worker=0), daemon=True)
+                drainers[t] = spec.name
+                self._threads.append(t)
             # mid-run spawns pick worker ids above every initial index so
             # consumer names never collide within a stage
             self._spawn_seq = max(self._desired.values(), default=1)
@@ -1150,7 +1192,15 @@ class StageRunner:
             with self._pool_lock:
                 threads = list(self._threads)
             for w in threads:
-                w.join(timeout=5.0)
+                if w not in drainers:
+                    w.join(timeout=5.0)
+                    continue
+                # a streaming train stage drains the rows left at stop;
+                # cutting that short would drop its updates in silence
+                w.join(timeout=STEP_ROWS_TIMEOUT_S)
+                if w.is_alive():
+                    self._fail(drainers[w], 0, TimeoutError(
+                        f"still draining after {STEP_ROWS_TIMEOUT_S:.0f} s"))
             feeder.join(timeout=5.0)
             if monitor is not None:
                 monitor.join(timeout=5.0)
@@ -1170,6 +1220,10 @@ class StageRunner:
                 sampler.stop()
         if self._error is not None:
             raise RuntimeError(f"stage-graph run failed: {self._error}")
+        if self._train_step < self.cfg.num_steps:
+            raise RuntimeError(f"stage-graph run stopped after "
+                               f"{self._train_step} of {self.cfg.num_steps} "
+                               f"steps")
         wall = time.monotonic() - t0
         n = self.samples_trained
         return WorkflowResult(

@@ -25,8 +25,10 @@ class ByteTokenizer:
         return np.asarray(ids, np.int32)
 
     def decode(self, ids) -> str:
+        """Bytes of the ids in the byte range; specials and ids of a model
+        vocabulary larger than this tokenizer's are skipped."""
         bs = bytes(int(i) - N_SPECIALS for i in ids
-                   if int(i) >= N_SPECIALS)
+                   if N_SPECIALS <= int(i) < self.vocab_size)
         return bs.decode("utf-8", errors="replace")
 
     def pad_batch(self, seqs: List[np.ndarray], length: int | None = None,

@@ -22,7 +22,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import act_fn, dense
@@ -61,7 +60,7 @@ def ep_moe_ffn(p, x, cfg, *, mesh, ep_axis: str = "model",
     d = cfg.d_model
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, None, None),                 # router w (replicated)
                   {"up": P(ep_axis, None, None),
                    "down": P(ep_axis, None, None),
@@ -69,7 +68,7 @@ def ep_moe_ffn(p, x, cfg, *, mesh, ep_axis: str = "model",
                      if "gate" in p["experts"] else {})},
                   P(dp_axis, None, None)),             # x
         out_specs=P(dp_axis, None, None),
-        check_rep=False)
+        check_vma=False)
     def _inner(router_w, experts, x):
         B, S, _ = x.shape
         N = B * S

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -50,11 +49,11 @@ def sharded_decode_attention(q, k_cache, v_cache, valid, *, mesh,
     ``seq_axis``. q replicated along that axis; returns (B,1,H,hd)."""
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(None, seq_axis, None, None),
                   P(None, seq_axis, None, None), P(None, seq_axis)),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def _inner(q, k, v, valid):
         m, l, acc = _partial_attention(q, k, v, valid)
         m_star = jax.lax.pmax(m, seq_axis)                  # (B,H)

@@ -35,6 +35,7 @@ from repro.engines.adapter import EngineRegistry, RLAdapter
 from repro.rl.advantage import grpo_advantages
 from repro.rl.reward import math_reward
 from repro.rl.sampling import generate as sample_generate
+from repro.rl.sampling import generate_bucket
 
 
 @EngineRegistry.register("jax_rollout")
@@ -122,6 +123,22 @@ class JaxRolloutEngine(RLAdapter):
                 emit(r)
             return []
         return rows
+
+    def warm_up(self, params, prompts: List[dict], max_prompts: int) -> None:
+        """Compile generation once for each bucket a call of 1 to
+        ``max_prompts`` of ``prompts`` can land in. A first compile at
+        full width outlasts the heartbeat and queue timeouts that guard a
+        running fleet, so it is done before the fleet starts. The
+        continuous backend and partial rollout compile as they go."""
+        if self.backend != "fixed" or self.chunk_tokens or not prompts:
+            return
+        lens = {len(p["tokens"]) for p in prompts}
+        buckets = {generate_bucket(k * self.group_size, n)
+                   for k in range(1, max_prompts + 1) for n in lens}
+        for b, n in sorted(buckets):
+            sample_generate(params, self.cfg, [np.zeros(n, np.int32)] * b,
+                            0, max_new_tokens=self.max_new_tokens,
+                            temperature=self.temperature)
 
     # ------------------------------------------------------------------ #
     # continuous-batching backend                                         #

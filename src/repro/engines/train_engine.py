@@ -171,6 +171,23 @@ class JaxTrainEngine(_AccumulatingEngine):
     def _grad(self, jb):
         return self._grad_fn(self.state.params, self.cfg, self.rl, jb)
 
+    def warm_up(self, row_counts) -> None:
+        """Compile the grad step for each micro-batch row count, and the
+        optimizer step, before the stage threads start. The engine's
+        state is left as it was."""
+        S = self.seq_len
+        grads = None
+        for n in row_counts:
+            zeros = [np.zeros(S, np.float32)] * n
+            batch = {"response": [np.zeros(S, np.int32)] * n,
+                     "response_mask": zeros, "logprob": zeros,
+                     "advantage": (zeros if self.algorithm == "ppo"
+                                   else [0.0] * n)}
+            if self.rl.kl_coef > 0:
+                batch["ref_logprob"] = zeros
+            grads, _ = self._grad(pack_rows(batch, S))
+        jax.block_until_ready(_apply(self.state, grads, 1.0, self.opt_cfg))
+
     def update(self, batch: Dict[str, list]) -> dict:
         return self._consume(batch)
 

@@ -28,25 +28,25 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (1, hd) row
+    q = q_ref[0, 0].astype(jnp.float32)              # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)              # (BK, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    valid = valid_ref[0]                             # (BK,)
+    valid = valid_ref[0]                             # (1, BK)
 
-    s = (k @ q[0]) * scale                           # (BK,)
-    s = jnp.where(valid, s, NEG_INF)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    s = jnp.where(valid != 0, s, NEG_INF)            # (1, BK)
 
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, s.max())
+    m_prev = m_ref[...]                              # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[0] = alpha * l_ref[0] + p.sum()
-    acc_ref[...] = acc_ref[...] * alpha + (p[:, None] * v).sum(0, keepdims=True)
-    m_ref[0] = m_new
+    l_ref[...] = alpha * l_ref[...] + p.sum(-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + p @ v
+    m_ref[...] = m_new
 
     @pl.when(j == num_k_blocks - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[0], 1e-30)).astype(
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -66,6 +66,9 @@ def decode_attention_kernel(q, k_cache, v_cache, valid, *, block_k=512,
     qt = q.transpose(0, 2, 1, 3)                      # (B,H,1,hd)
     kt = k_cache.transpose(0, 2, 1, 3)                # (B,KVH,S,hd)
     vt = v_cache.transpose(0, 2, 1, 3)
+    # (B, 1, S) int32: a (1, BK) block of it spans the sublane dim, which
+    # a (1, BK) block of the (B, S) mask does not
+    vm = valid.astype(jnp.int32)[:, None, :]
 
     kernel = functools.partial(_decode_kernel, num_k_blocks=nk,
                                scale=hd ** -0.5)
@@ -78,15 +81,15 @@ def decode_attention_kernel(q, k_cache, v_cache, valid, *, block_k=512,
                          lambda b, h, j, n_rep=n_rep: (b, h // n_rep, j, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, h, j, n_rep=n_rep: (b, h // n_rep, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt, valid)
+    )(qt, kt, vt, vm)
     return out.transpose(0, 2, 1, 3)

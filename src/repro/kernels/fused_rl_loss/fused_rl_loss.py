@@ -12,7 +12,8 @@ skeleton):
 
   m, l   — running max / rescaled Σ exp (online log-sum-exp)
   t      — running Σ exp(x − m)·x            (entropy)
-  g      — the target token's logit          (picked up as its block goes by)
+  g      — the target token's logit          (iota-compare pick, summed
+                                              over blocks)
 
 and, on the last vocab block, finishes the whole per-token epilogue in
 registers: logprob ``lp = g − lse``, entropy ``ent = lse − t/l``, k3 KL
@@ -49,6 +50,14 @@ from repro.kernels.pad_utils import (NEG_INF, pad_logits, pad_rows,
                                      pick_blocks)
 
 
+def _pick_target(x, local):
+    """x[r, local[r]] for (BN, BV) x and (BN, 1) block-local targets; 0
+    where the target is not in this block. An iota compare and a row sum
+    stand in for a gather, which Mosaic does not lower."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(cols == local, x, 0.0).sum(-1, keepdims=True)
+
+
 def _fwd_kernel(logits_ref, target_ref, old_ref, ref_ref, adv_ref,
                 lp_ref, ent_ref, kl_ref, pl_ref, ratio_ref, lse_ref,
                 m_ref, l_ref, t_ref, g_ref, *,
@@ -63,22 +72,17 @@ def _fwd_kernel(logits_ref, target_ref, old_ref, ref_ref, adv_ref,
         g_ref[...] = jnp.zeros_like(g_ref)
 
     x = logits_ref[...].astype(jnp.float32)          # (BN, BV)
-    tgt = target_ref[...]                            # (BN,)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, x.max(-1))
+    m_prev = m_ref[...]                              # (BN, 1)
+    m_new = jnp.maximum(m_prev, x.max(-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(x - m_new[:, None])
-    l_ref[...] = alpha * l_ref[...] + p.sum(-1)
-    t_ref[...] = alpha * t_ref[...] + (p * x).sum(-1)
+    p = jnp.exp(x - m_new)
+    l_ref[...] = alpha * l_ref[...] + p.sum(-1, keepdims=True)
+    t_ref[...] = alpha * t_ref[...] + (p * x).sum(-1, keepdims=True)
     m_ref[...] = m_new
-
-    v0 = jv * block_v
-    local = tgt - v0
-    in_block = (local >= 0) & (local < block_v)
-    idx = jnp.clip(local, 0, block_v - 1)
-    picked = jnp.take_along_axis(x, idx[:, None], axis=1)[:, 0]
-    g_ref[...] = jnp.where(in_block, picked, g_ref[...])
+    # the target sits in exactly one vocab block: summing the per-block
+    # picks over all blocks yields its logit
+    g_ref[...] += _pick_target(x, target_ref[...] - jv * block_v)
 
     @pl.when(jv == num_v_blocks - 1)
     def _finish():
@@ -109,16 +113,26 @@ def _bwd_kernel(logits_ref, target_ref, lse_ref, xbar_ref, dlp_ref,
                 gent_ref, dx_ref, *, block_v):
     jv = pl.program_id(1)
     x = logits_ref[...].astype(jnp.float32)          # (BN, BV)
-    p = jnp.exp(x - lse_ref[...][:, None])           # softmax, recomputed
+    p = jnp.exp(x - lse_ref[...])                    # softmax, recomputed
 
-    local = target_ref[...] - jv * block_v
+    local = target_ref[...] - jv * block_v           # (BN, 1)
     onehot = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-              == local[:, None]).astype(jnp.float32)
+              == local).astype(jnp.float32)
 
-    dlp = dlp_ref[...][:, None]
-    gent = gent_ref[...][:, None]
-    dx = dlp * onehot - p * (dlp + gent * (x - xbar_ref[...][:, None]))
+    dlp = dlp_ref[...]
+    dx = dlp * onehot - p * (dlp + gent_ref[...] * (x - xbar_ref[...]))
     dx_ref[...] = dx.astype(dx_ref.dtype)
+
+
+def _row_spec(bn):
+    """Per-row vectors travel as (N, 1) columns: a 2-D block whose lane
+    dim spans the array is tiled alike by XLA and Mosaic, where a 1-D
+    (BN,) block is not."""
+    return pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
+
+
+def _col(x, n_pad):
+    return pad_rows(x, n_pad)[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("clip_eps", "block_n",
@@ -134,25 +148,22 @@ def fused_rl_loss_fwd_kernel(logits, targets, old_logprob, ref_logprob,
     nn, nv = n_pad // bn, v_pad // bv
 
     lg = pad_logits(logits, n_pad, v_pad)
-    tg = pad_rows(targets, n_pad)
-    old = pad_rows(old_logprob, n_pad)
-    ref = pad_rows(ref_logprob, n_pad)
-    adv = pad_rows(advantage, n_pad)
+    rows = [_col(a, n_pad) for a in (targets, old_logprob, ref_logprob,
+                                     advantage)]
 
     kernel = functools.partial(_fwd_kernel, block_v=bv, num_v_blocks=nv,
                                clip_eps=float(clip_eps))
-    row = pl.BlockSpec((bn,), lambda i, j: (i,))
+    row = _row_spec(bn)
     outs = pl.pallas_call(
         kernel,
         grid=(nn, nv),
-        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-                  row, row, row, row],
+        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j))] + [row] * 4,
         out_specs=[row] * 6,
-        out_shape=[jax.ShapeDtypeStruct((n_pad,), jnp.float32)] * 6,
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.float32)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((n_pad, 1), jnp.float32)] * 6,
+        scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 4,
         interpret=interpret,
-    )(lg, tg, old, ref, adv)
-    return tuple(o[:N] for o in outs)
+    )(lg, *rows)
+    return tuple(o[:N, 0] for o in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_v",
@@ -165,23 +176,18 @@ def fused_rl_loss_bwd_kernel(logits, targets, lse, xbar, dlp, g_ent, *,
     nn, nv = n_pad // bn, v_pad // bv
 
     lg = pad_logits(logits, n_pad, v_pad)
-    tg = pad_rows(targets, n_pad)
-    # padded rows: lse=0 would make p = exp(0-0) = 1 — harmless (their
-    # dlp/g_ent are 0 and the rows are sliced off), but keep exp bounded
-    ls = pad_rows(lse, n_pad)
-    xb = pad_rows(xbar, n_pad)
-    dl = pad_rows(dlp, n_pad)
-    ge = pad_rows(g_ent, n_pad)
+    # padded rows: lse=0 makes p = exp(0-0) = 1 — harmless (their
+    # dlp/g_ent are 0 and the rows are sliced off)
+    rows = [_col(a, n_pad) for a in (targets, lse, xbar, dlp, g_ent)]
 
     kernel = functools.partial(_bwd_kernel, block_v=bv)
-    row = pl.BlockSpec((bn,), lambda i, j: (i,))
+    row = _row_spec(bn)
     dx = pl.pallas_call(
         kernel,
         grid=(nn, nv),
-        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
-                  row, row, row, row, row],
+        in_specs=[pl.BlockSpec((bn, bv), lambda i, j: (i, j))] + [row] * 5,
         out_specs=pl.BlockSpec((bn, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, v_pad), logits.dtype),
         interpret=interpret,
-    )(lg, tg, ls, xb, dl, ge)
+    )(lg, *rows)
     return dx[:N, :V]

@@ -23,15 +23,15 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _round_up(n: int, mult: int) -> int:
+def round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
 def pick_blocks(n: int, v: int, block_n: int, block_v: int):
     """Return (bn, bv, n_pad, v_pad): block sizes + padded extents."""
-    bn = min(block_n, _round_up(n, 8))
-    bv = min(block_v, _round_up(v, 128))
-    return bn, bv, _round_up(n, bn), _round_up(v, bv)
+    bn = min(block_n, round_up(n, 8))
+    bv = min(block_v, round_up(v, 128))
+    return bn, bv, round_up(n, bn), round_up(v, bv)
 
 
 def pad_logits(x, n_pad: int, v_pad: int):

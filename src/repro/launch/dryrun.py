@@ -12,6 +12,7 @@ import os
 
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_FLAGS",
                            "--xla_force_host_platform_device_count=512"))
+os.environ["JAX_PLATFORMS"] = "cpu"      # fake devices; never the chip
 
 # ruff: noqa: E402
 import argparse

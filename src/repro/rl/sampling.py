@@ -65,6 +65,13 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+def generate_bucket(n_seqs: int, prompt_len: int) -> tuple:
+    """(batch, prompt length) a bucketed ``generate`` call of ``n_seqs``
+    prompts, the longest ``prompt_len`` tokens, is padded to: one XLA
+    compilation per bucket."""
+    return _next_pow2(n_seqs), ((prompt_len + 7) // 8) * 8
+
+
 def generate(params, cfg, prompts: List[np.ndarray], rng_seed: int, *,
              max_new_tokens: int = 16, temperature: float = 1.0,
              eos_id: int = ByteTokenizer.eos_id,
@@ -79,10 +86,9 @@ def generate(params, cfg, prompts: List[np.ndarray], rng_seed: int, *,
     n_real = len(prompts)
     prompts = list(prompts)
     if bucket:
-        target_b = _next_pow2(n_real)
+        target_b, pad_len = generate_bucket(
+            n_real, max(len(p) for p in prompts))
         prompts += [prompts[-1]] * (target_b - n_real)
-        max_len = max(len(p) for p in prompts)
-        pad_len = ((max_len + 7) // 8) * 8
         toks, mask = tok.pad_batch(prompts, length=pad_len)
     else:
         toks, mask = tok.pad_batch(prompts)

@@ -47,6 +47,27 @@ def test_flash_attention_odd_shape_falls_back():
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("window", [0, 64])
+def test_flash_attention_pads_untiled_length(window):
+    """S=200 does not divide the 128 block: the kernel runs on a padded
+    sequence instead of handing the call to the oracle."""
+    from repro.kernels.flash_attention import flash_attention, \
+        flash_attention_ref
+    q = jax.random.normal(k(1), (1, 200, 4, 32))
+    kv = jax.random.normal(k(2), (1, 200, 2, 32))
+    np.testing.assert_allclose(flash_attention(q, kv, kv, window=window),
+                               flash_attention_ref(q, kv, kv, window=window),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_flash_attention_untiled_cross_lengths_raise():
+    from repro.kernels.flash_attention import flash_attention
+    q = jax.random.normal(k(1), (1, 200, 2, 16))
+    kv = jax.random.normal(k(2), (1, 300, 2, 16))
+    with pytest.raises(ValueError, match="do not tile"):
+        flash_attention(q, kv, kv)
+
+
 # ---------------------------------------------------------------------------
 # decode_attention
 # ---------------------------------------------------------------------------
@@ -71,6 +92,21 @@ def test_decode_attention(B, S, H, KV, hd, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+
+
+def test_decode_attention_pads_untiled_cache():
+    """A 700-long cache does not divide the 512 block: it is padded with
+    invalid positions rather than sent to the oracle."""
+    from repro.kernels.decode_attention import (decode_attention,
+                                                decode_attention_ref)
+    B, S, H, KV, hd = 2, 700, 4, 2, 32
+    q = jax.random.normal(k(1), (B, 1, H, hd))
+    kc = jax.random.normal(k(2), (B, S, KV, hd))
+    vc = jax.random.normal(k(3), (B, S, KV, hd))
+    valid = jnp.arange(S)[None, :] < jnp.asarray([[650], [700]])
+    np.testing.assert_allclose(decode_attention(q, kc, vc, valid),
+                               decode_attention_ref(q, kc, vc, valid),
+                               atol=2e-5, rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -64,6 +64,15 @@ def test_math_reward():
     assert math_reward(12, tok.encode("123", add_bos=False)) < 1.0
 
 
+def test_math_reward_skips_ids_beyond_the_byte_vocabulary():
+    """A model vocabulary larger than the byte tokenizer's samples ids the
+    tokenizer has no byte for; they are skipped, not a crash."""
+    ids = np.concatenate([[5000], tok.encode("12", add_bos=False),
+                          [ByteTokenizer.vocab_size, 152_063]])
+    assert tok.decode(ids) == "12"
+    assert math_reward(12, ids) == 1.0
+
+
 def _rl_batch(cfg, B=4, S=12, seed=0):
     rng = np.random.default_rng(seed)
     adv = rng.normal(size=B).astype(np.float32)

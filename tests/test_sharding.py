@@ -13,9 +13,8 @@ from repro.distributed.sharding import (batch_pspecs, cache_pspecs,
 from repro.launch.specs import (decode_specs, params_struct, state_struct,
                                 train_specs)
 
-# jax >= 0.4.35 takes a ((name, size), ...) shape tuple
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-POD_MESH = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _flat_with_paths(tree):

@@ -123,6 +123,123 @@ def test_stage_runner_toy_dataflow_streams_per_stage():
     assert min(e.start for e in enrich_ev) < max(e.end for e in gen_ev)
 
 
+@pytest.mark.parametrize("mode,rows", [("streaming", [2, 4]),
+                                       ("baseline", [6])])
+def test_stage_runner_warms_engines_before_stage_threads(mode, rows):
+    """run() first hands each engine that has a ``warm_up`` the shapes the
+    run gives it: every prompt fed, the most prompts per generate call,
+    and each row count the step driver fetches. The prompts it looked
+    ahead at are fed as they are, each step's fetched once."""
+    calls, streamed = [], []
+
+    def gen(batch, *, params, rng, version=0, **kw):
+        calls.append("generate")
+        return {"rows": [dict(item=x, token_len=1)
+                         for x in batch["prompt"] for _ in range(2)]}
+
+    def prompts(step):
+        streamed.append(step)
+        return [10 * step + i for i in range(3)]
+
+    g = _toy_graph()
+    g.stages["generate"] = dataclasses.replace(g.stages["generate"],
+                                               engine="rollout", fn=gen)
+    engines = {
+        "rollout": SimpleNamespace(warm_up=lambda params, ps, k: calls.append(
+            ("gen", sorted(ps), k))),
+        "trainer": SimpleNamespace(params={"w": 0}, warm_up=lambda r:
+                                   calls.append(("train", r)))}
+    cfg = WorkflowConfig(mode=mode, num_rollout_workers=1, rollout_batch=2,
+                         train_micro_batch=4, prompts_per_step=3,
+                         group_size=2, num_steps=2)
+    r = StageRunner(cfg, g, engines=engines, prompt_stream=prompts,
+                    metrics=MetricsRegistry()).run()
+    assert calls[:2] == [("gen", [0, 1, 2, 10, 11, 12], 2), ("train", rows)]
+    assert set(calls[2:]) == {"generate"}
+    assert streamed == [0, 1]
+    assert r.samples_trained == 2 * 6
+
+
+def test_rollout_warm_up_runs_each_generate_bucket_once(monkeypatch):
+    """Batches of 1..3 prompts x G=4 pad to 4, 8 and 16 rows; prompts of
+    5, 8 and 13 tokens pad to 8 and 16: six buckets, one call each."""
+    from repro.engines import rollout_engine
+    shapes = []
+    monkeypatch.setattr(rollout_engine, "sample_generate",
+                        lambda params, cfg, ps, seed, **kw: shapes.append(
+                            (len(ps), len(ps[0]))))
+    eng = JaxRolloutEngine(tiny_cfg(), group_size=4)
+    eng.warm_up({}, [{"tokens": [1] * n} for n in (5, 8, 13, 5)], 3)
+    assert sorted(shapes) == [(b, n) for b in (4, 8, 16) for n in (8, 16)]
+
+
+def test_stage_runner_raises_when_a_step_gets_no_rows(monkeypatch):
+    """A step driver that waits out its row timeout fails the run; it
+    does not return a result with fewer steps than asked for."""
+    from repro.core.workflow import stage_graph
+    monkeypatch.setattr(stage_graph, "STEP_ROWS_TIMEOUT_S", 0.3)
+
+    def gen_first_step_only(batch, *, params, rng, version=0, **kw):
+        return {"rows": [dict(item=x, token_len=1)
+                         for x in batch["prompt"] if x == 0
+                         for _ in range(2)]}
+
+    g = _toy_graph()
+    g.stages["generate"] = dataclasses.replace(g.stages["generate"],
+                                               fn=gen_first_step_only)
+    cfg = WorkflowConfig(mode="streaming", num_rollout_workers=1,
+                         rollout_batch=2, train_micro_batch=4,
+                         prompts_per_step=4, group_size=2, num_steps=3)
+    runner = StageRunner(
+        cfg, g, engines={"trainer": SimpleNamespace(params={"w": 0})},
+        prompt_stream=lambda s: [s] * 4, metrics=MetricsRegistry())
+    with pytest.raises(RuntimeError, match="step 1: no rows"):
+        runner.run()
+    assert runner.samples_trained == 8
+
+
+def _graph_with_slow_stream_stage(seconds):
+    g = _toy_graph()
+    calls = []
+
+    def slow_sink(batch, **kw):
+        if not calls:
+            time.sleep(seconds)
+        calls.append(len(batch["item"]))
+        return {"n": len(batch["item"])}
+
+    g.add(StageSpec("critic", inputs=("item", "score"), engine="",
+                    fn=slow_sink, kind="train_stream"))
+    return g, calls
+
+
+def _toy_runner(graph):
+    cfg = WorkflowConfig(mode="streaming", num_rollout_workers=1,
+                         rollout_batch=2, train_micro_batch=4,
+                         prompts_per_step=4, group_size=2, num_steps=2)
+    return StageRunner(
+        cfg, graph, engines={"trainer": SimpleNamespace(params={"w": 0})},
+        prompt_stream=lambda s: [1, 2, 3, 4], metrics=MetricsRegistry())
+
+
+def test_stage_runner_waits_for_streaming_train_stage_to_drain():
+    """A streaming train stage still busy when the last step ends drains
+    every row before run() returns (it used to be cut off at 5 s)."""
+    g, calls = _graph_with_slow_stream_stage(5.5)
+    r = _toy_runner(g).run()
+    assert sum(calls) == 2 * 8
+    assert sum(m["n"] for m in r.aux_metrics["critic"]) == 2 * 8
+
+
+def test_stage_runner_fails_a_streaming_stage_that_never_drains(
+        monkeypatch):
+    from repro.core.workflow import stage_graph
+    monkeypatch.setattr(stage_graph, "STEP_ROWS_TIMEOUT_S", 0.3)
+    g, _ = _graph_with_slow_stream_stage(1.5)
+    with pytest.raises(RuntimeError, match="'critic'.*still draining"):
+        _toy_runner(g).run()
+
+
 def test_stage_runner_auto_sizes_zero_worker_stages():
     """auto_size_workers=True planner-sizes every stage left at
     num_workers=0 and the run still trains the exact sample count."""

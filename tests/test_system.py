@@ -11,6 +11,8 @@ import pytest
 
 from repro.api import AsyncFlowService, Trainer, TrainerConfig
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _fit(mode, steps=3):
     tcfg = TrainerConfig(arch="qwen2_5_7b", mode=mode, num_steps=steps,
@@ -109,3 +111,57 @@ def test_trainer_checkpoint_roundtrip(tmp_path):
     for a, b in zip(jax.tree.leaves(t.train_engine.state.params),
                     jax.tree.leaves(t2.train_engine.state.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _chip_smoke():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO_ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_run_at_tiny_width_with_2048_vocab():
+    """The chip smoke's checks, on CPU at a tiny width: the Trainer runs
+    through reward with a 2,048-id vocabulary (sampled ids past the byte
+    tokenizer's 259) and the fused Pallas loss in interpret mode."""
+    from conftest import tiny_cfg
+    smoke = _chip_smoke()
+    tcfg = TrainerConfig(**{**smoke.TRAFFIC, "max_new_tokens": 8,
+                            "seq_len": 24})
+    lines = []
+    assert smoke.run(tiny_cfg(vocab_size=2048), tcfg, log=lines.append), \
+        "\n".join(lines)
+    assert any(ln.startswith("check samples trained: ok 48 of 48")
+               for ln in lines)
+
+
+def test_chip_smoke_refuses_a_host_without_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO_ROOT,
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+@pytest.mark.parametrize("env_dir", ["", "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set; otherwise the
+    cache sits at the fixed <repo>/.jax_cache."""
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+    set_calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: set_calls.append((k, v)))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert use_compile_cache() == env_dir
+        assert set_calls == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO_ROOT, ".jax_cache")
+        assert use_compile_cache() == want
+        assert set_calls == [("jax_compilation_cache_dir", want)]

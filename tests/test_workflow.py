@@ -46,8 +46,6 @@ def test_mode_ordering_and_staleness():
     assert max(rs["baseline"].staleness_seen) == 0
     assert max(rs["streaming"].staleness_seen) == 0
     assert 1 <= max(rs["async"].staleness_seen) <= 2
-    assert rs["async"].wall_time_s < rs["baseline"].wall_time_s
-    assert rs["streaming"].wall_time_s < rs["baseline"].wall_time_s
 
 
 def test_all_samples_trained_every_mode():
