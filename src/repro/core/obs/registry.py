@@ -213,6 +213,17 @@ class Histogram(_Metric):
             "p99": quantile(xs, 0.99),
         }
 
+    def recent(self, n: int, **labels) -> List[float]:
+        """The last ``n`` observations still in the ring, oldest first."""
+        with self._lock:
+            s = self._series.get(_label_key(labels))
+            if s is None:
+                return []
+            full = len(s.samples) == self.max_samples
+            k = s.count % self.max_samples if full else 0   # the oldest
+            ordered = s.samples[k:] + s.samples[:k]
+        return ordered[max(0, len(ordered) - n):]
+
     def summary(self, **labels) -> dict:
         with self._lock:
             s = self._series.get(_label_key(labels))

@@ -4,10 +4,11 @@
 ``build_telemetry`` folds the run's :class:`EventLog` plus the metrics
 registry into one JSON-safe dict:
 
-* ``stages``    — one row per stage kind: worker count, busy seconds,
+* ``stages``    — one row per top-level host-work span kind (the
+  event log's kind table): its layer, worker count, busy seconds,
   samples processed, samples/s against the run wall clock.
 * ``instances`` — one row per worker instance: busy % (overlap-merged)
-  and wait % (blocked fetch + weight sync).
+  and wait % (blocked kinds: fetch, weight waits).
 * ``staleness`` — p50/p95/max of observed weight staleness at the
   consuming train stage.
 * ``metrics``   — the raw ``MetricsRegistry.snapshot()``.
@@ -21,8 +22,6 @@ from typing import Dict, List, Optional
 
 from repro.core.obs.registry import MetricsRegistry, quantile
 
-BOOKKEEPING_KINDS = ("wait", "weight_sync")
-
 
 def build_telemetry(log, registry: Optional[MetricsRegistry],
                     wall_time_s: float, samples_trained: int,
@@ -32,11 +31,11 @@ def build_telemetry(log, registry: Optional[MetricsRegistry],
 
     by_kind: Dict[str, dict] = {}
     for e in events:
-        if e.kind in BOOKKEEPING_KINDS:
+        if e.parent is not None or e.info.blocked:
             continue
         row = by_kind.setdefault(e.kind, {
-            "stage": e.kind, "workers": set(), "calls": 0,
-            "busy_s": 0.0, "samples": 0})
+            "stage": e.kind, "layer": e.info.layer, "workers": set(),
+            "calls": 0, "busy_s": 0.0, "samples": 0})
         row["workers"].add(e.instance)
         row["calls"] += 1
         row["busy_s"] += e.duration
@@ -47,6 +46,7 @@ def build_telemetry(log, registry: Optional[MetricsRegistry],
         row = by_kind[kind]
         stages.append({
             "stage": kind,
+            "layer": row["layer"],
             "workers": len(row["workers"]),
             "calls": row["calls"],
             "busy_s": round(row["busy_s"], 4),
@@ -88,12 +88,13 @@ def render_report(telemetry: dict) -> str:
         f"{telemetry['samples_trained']} samples · "
         f"{telemetry['throughput']:.1f} samples/s",
         "",
-        f"{'stage':>16s} {'workers':>7s} {'calls':>6s} {'busy_s':>8s} "
-        f"{'samples':>8s} {'samples/s':>10s}",
+        f"{'stage':>16s} {'layer':>12s} {'workers':>7s} {'calls':>6s} "
+        f"{'busy_s':>8s} {'samples':>8s} {'samples/s':>10s}",
     ]
     for row in telemetry.get("stages", []):
         lines.append(
-            f"{row['stage']:>16s} {row['workers']:>7d} {row['calls']:>6d} "
+            f"{row['stage']:>16s} {row.get('layer', ''):>12s} "
+            f"{row['workers']:>7d} {row['calls']:>6d} "
             f"{row['busy_s']:>8.2f} {row['samples']:>8d} "
             f"{row['samples_per_s']:>10.1f}")
     lines += ["", f"{'instance':>16s} {'busy %':>7s} {'wait %':>7s}"]

@@ -61,7 +61,7 @@ class TransferQueueController:
         # incremental bookkeeping: O(1) notify, O(avail) schedule — the
         # §3.5 high-concurrency design (no O(capacity) metadata scans)
         self._n_ready_cols = [0] * capacity
-        self._avail: Dict[int, None] = {}   # insertion-ordered set
+        self._avail: Dict[int, float] = {}  # ready row -> when it became so
         self._token_len: Dict[int, int] = {}
         self._tokens_served: Dict[str, int] = {}
         self._lock = threading.Lock()
@@ -78,12 +78,6 @@ class TransferQueueController:
         self.metrics = m
         # pre-bound series (labels sorted once) — cheap enough to update
         # inside the scheduling lock
-        self._m_requests = m.counter(
-            "tq_requests_total", "scheduling requests per task").labels(
-            task=task)
-        self._m_rows_ready = m.counter(
-            "tq_rows_ready_total",
-            "rows that became schedulable per task").labels(task=task)
         self._m_rows_consumed = m.counter(
             "tq_rows_consumed_total", "rows handed to consumers per task"
         ).labels(task=task)
@@ -99,6 +93,10 @@ class TransferQueueController:
         self._m_wait = m.counter(
             "tq_blocked_wait_seconds_total",
             "seconds consumers spent blocked on this task")
+        self._m_row_wait = m.histogram(
+            "tq_row_wait_seconds",
+            "per row, seconds from ready (its last column written) to "
+            "handed out").labels(task=task)
         self._m_requeued = m.counter(
             "rows_requeued_total",
             "leased rows returned to ready after a consumer death"
@@ -112,8 +110,7 @@ class TransferQueueController:
             self._n_ready_cols[idx] += 1
             if self._n_ready_cols[idx] == len(self.columns) \
                     and not self._consumed[idx]:
-                self._avail[idx] = None
-                self._m_rows_ready.inc()
+                self._avail[idx] = time.monotonic()
                 self._m_depth.set(len(self._avail))
 
     def notify(self, idx: int, column: str) -> None:
@@ -159,7 +156,6 @@ class TransferQueueController:
         deadline = None if timeout is None else t0 + timeout
         with self._cv:
             self.n_requests += 1
-            self._m_requests.inc()
             while True:
                 n_avail = len(self._avail)
                 if n_avail >= batch_size or \
@@ -186,9 +182,10 @@ class TransferQueueController:
                                         consumer)
             else:
                 chosen = list(itertools.islice(self._avail, batch_size))
+            now = time.monotonic()
             for i in chosen:
                 self._consumed[i] = True
-                self._avail.pop(i, None)
+                self._m_row_wait.observe(now - self._avail.pop(i, now))
             self._m_sched.inc(task=self.task,
                               policy="token_balance" if use_tb else "fifo")
             self._m_rows_consumed.inc(len(chosen))
@@ -252,13 +249,14 @@ class TransferQueueController:
             if rec is None:
                 return 0
             rows = [i for i in rec["rows"] if self._consumed[i]]
-            front: Dict[int, None] = {}
+            front: Dict[int, float] = {}
+            now = time.monotonic()
             for i in rows:
                 self._consumed[i] = False
                 if self._n_ready_cols[i] == len(self.columns):
-                    front[i] = None
-            for i in self._avail:
-                front.setdefault(i, None)
+                    front[i] = now
+            for i, t in self._avail.items():
+                front.setdefault(i, t)
             self._avail = front
             self._m_requeued.inc(len(rows))
             self._m_depth.set(len(self._avail))

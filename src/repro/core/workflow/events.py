@@ -1,11 +1,17 @@
 """Execution-timeline event log → Gantt chart / bubble-fraction analysis
 (paper Fig. 11) and Perfetto-loadable Chrome trace export.
 
-Stage-graph workers record spans under their stage name (``generate``,
-``ref_inference``, ``reward``, ``advantage``, ``values``, ``update``,
-``critic_update``, ...), so per-stage pipeline overlap is directly
-visible. Any kind that is not bookkeeping (``wait`` / ``weight_sync``)
-counts as busy time — custom stage names are busy by default.
+Spans come from the one span primitive, :class:`repro.core.obs.span`
+(``EventLog.span`` calls it): each is also a profiler annotation
+``asyncflow.<kind>`` on the device trace's clock, and records the kind
+of its enclosing span as ``parent``. Stage-graph workers record spans
+under their stage name (``generate``, ``ref_inference``, ``reward``,
+``advantage``, ``values``, ``update``, ``critic_update``, ...); the
+engines and the weight path record the parts inside them. ``KINDS``
+lists every kind once with its layer and whether it is blocked; a kind
+it does not list (a custom stage) is host work of no listed layer.
+Busy, wait and per-stage figures read top-level spans only: a nested
+span's time is already inside its parent's.
 
 ``to_chrome_trace()`` emits the same spans as ``traceEvents`` JSON
 (complete ``"X"`` events keyed by instance, meta as ``args``) loadable
@@ -20,7 +26,56 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-IDLE_KINDS = ("wait", "weight_sync")
+from repro.core.obs.spans import span as _span
+
+
+@dataclass(frozen=True)
+class Kind:
+    """``layer``: the layer a span's time, and device idle under it, is
+    charged to; a blocked kind is charged to the work it waits on.
+    ``blocked``: the span waits on the device, the queue or new weights
+    rather than doing host work."""
+    layer: str
+    blocked: bool = False
+
+
+ROLLOUT, UPDATE, WEIGHT_SYNC = "rollout", "actor update", "weight sync"
+
+KINDS: Dict[str, Kind] = {
+    # rollout: the generate stage, its parts, and the stages that finish
+    # its rows
+    "generate": Kind(ROLLOUT),
+    "generate.prepare": Kind(ROLLOUT),     # pad, bucket, copy in, dispatch
+    "generate.device": Kind(ROLLOUT, blocked=True),  # reading outputs back
+    "generate.rows": Kind(ROLLOUT),        # EOS trim, row dicts, emit
+    "ref_inference": Kind(ROLLOUT),
+    "reward": Kind(ROLLOUT),
+    "advantage": Kind(ROLLOUT),
+    "values": Kind(ROLLOUT),
+    # the step driver's wait for rows: charged to the rollout making them
+    "wait": Kind(ROLLOUT, blocked=True),
+    # actor update: the driver's update and its parts, and the critic's
+    "update": Kind(UPDATE),
+    "update.pack": Kind(UPDATE),           # rows to device arrays
+    "update.grad": Kind(UPDATE),           # dispatch + metrics read-back
+    "update.accumulate": Kind(UPDATE),     # eager add, inside update.grad
+    "update.optimizer": Kind(UPDATE),      # optimizer step + gnorm read
+    "critic_update": Kind(UPDATE),
+    # weight sync
+    "weight_sync": Kind(WEIGHT_SYNC, blocked=True),  # driver's hand-off
+    "publish.wait": Kind(WEIGHT_SYNC, blocked=True),  # params computed
+    "publish.copy": Kind(WEIGHT_SYNC),     # device to host, channel offer
+    "weight_swap": Kind(WEIGHT_SYNC),      # host to device (dispatch)
+    "staleness_wait": Kind(WEIGHT_SYNC, blocked=True),
+    # a program compiled or loaded (recorded when it ends)
+    "compile": Kind("device"),
+}
+_OTHER = Kind("other")
+
+
+def kind_info(kind: str) -> Kind:
+    return KINDS.get(kind, _OTHER)
+
 
 # stable symbols for the built-in stage kinds; custom stages draw from
 # _CUSTOM_PALETTE in registration order (see register_kinds)
@@ -66,10 +121,15 @@ class Event:
     start: float
     end: float
     meta: dict = field(default_factory=dict)
+    parent: Optional[str] = None   # kind of the enclosing span
 
     @property
     def duration(self) -> float:
         return self.end - self.start
+
+    @property
+    def info(self) -> Kind:
+        return kind_info(self.kind)
 
 
 class EventLog:
@@ -80,10 +140,10 @@ class EventLog:
         self.t0 = time.monotonic()
 
     def record(self, instance: str, kind: str, start: float, end: float,
-               **meta) -> None:
+               parent: Optional[str] = None, **meta) -> None:
         with self._lock:
             self._events.append(Event(instance, kind, start - self.t0,
-                                      end - self.t0, meta))
+                                      end - self.t0, meta, parent))
 
     def register_kinds(self, kinds: Sequence[str]) -> None:
         """Declare stage kinds up front (StageRunner registers the graph's
@@ -93,20 +153,9 @@ class EventLog:
             for k in kinds:
                 self._kind_order.setdefault(k, None)
 
-    class _Span:
-        def __init__(self, log, instance, kind, meta):
-            self.log, self.instance, self.kind, self.meta = log, instance, kind, meta
-
-        def __enter__(self):
-            self.start = time.monotonic()
-            return self
-
-        def __exit__(self, *exc):
-            self.log.record(self.instance, self.kind, self.start,
-                            time.monotonic(), **self.meta)
-
-    def span(self, instance: str, kind: str, **meta) -> "_Span":
-        return self._Span(self, instance, kind, meta)
+    def span(self, instance: str, kind: str, **meta) -> _span:
+        """The span primitive, recording into this log."""
+        return _span(kind, instance=instance, log=self, **meta)
 
     # -- analysis ---------------------------------------------------------
 
@@ -126,23 +175,23 @@ class EventLog:
         if not ev:
             return 0.0
         span = max(e.end for e in ev) - min(e.start for e in ev)
-        sel = _merged_total([(e.start, e.end) for e in ev if selector(e)])
+        sel = _merged_total([(e.start, e.end) for e in ev
+                             if e.parent is None and selector(e)])
         return sel / max(span, 1e-9)
 
     def busy_fraction(self, instance: str, busy_kinds=None) -> float:
-        """busy_kinds=None counts every kind except IDLE_KINDS as busy.
+        """busy_kinds=None counts every kind that is not blocked as busy.
 
         Overlapping spans (multiple workers recorded under one instance)
         are merged before summing, so the fraction never exceeds 1."""
         if busy_kinds is None:
-            return self._fraction(instance,
-                                  lambda e: e.kind not in IDLE_KINDS)
+            return self._fraction(instance, lambda e: not e.info.blocked)
         return self._fraction(instance, lambda e: e.kind in busy_kinds)
 
     def wait_fraction(self, instance: str) -> float:
-        """Fraction of the instance's span spent in bookkeeping waits
-        (blocked fetches + weight sync), overlap-merged."""
-        return self._fraction(instance, lambda e: e.kind in IDLE_KINDS)
+        """Fraction of the instance's span spent in blocked kinds
+        (blocked fetches, weight waits), overlap-merged."""
+        return self._fraction(instance, lambda e: e.info.blocked)
 
     def bubble_fraction(self, busy_kinds=None) -> Dict[str, float]:
         return {i: 1.0 - self.busy_fraction(i, busy_kinds)
@@ -169,7 +218,7 @@ class EventLog:
         for e in self.events():
             trace.append({
                 "name": e.kind,
-                "cat": "idle" if e.kind in IDLE_KINDS else "stage",
+                "cat": "idle" if e.info.blocked else "stage",
                 "ph": "X",
                 "ts": round(e.start * 1e6, 3),
                 "dur": round(max(e.duration, 0.0) * 1e6, 3),
@@ -203,8 +252,8 @@ class EventLog:
         return sym
 
     def render_gantt(self, width: int = 80, busy_kinds=None) -> str:
-        """ASCII Gantt chart (Fig. 11 analogue)."""
-        ev = self.events()
+        """ASCII Gantt chart (Fig. 11 analogue) of the top-level spans."""
+        ev = [e for e in self.events() if e.parent is None]
         if not ev:
             return "(no events)"
         t_min = min(e.start for e in ev)
@@ -212,9 +261,11 @@ class EventLog:
         scale = width / max(t_max - t_min, 1e-9)
         sym = self._symbols(ev)
         lines = []
-        for inst in self.instances():
+        for inst in sorted({e.instance for e in ev}):
             row = [" "] * width
-            for e in self.events(inst):
+            for e in ev:
+                if e.instance != inst:
+                    continue
                 a = int((e.start - t_min) * scale)
                 b = max(a + 1, int((e.end - t_min) * scale))
                 ch = sym.get(e.kind, "#")

@@ -43,8 +43,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.obs import (MetricsRegistry, MetricsSampler, build_telemetry,
-                            get_registry)
+from repro.core.obs import (MetricsRegistry, MetricsSampler, bind_instance,
+                            build_telemetry, get_registry)
+from repro.core.obs import spans
 from repro.core.supervision import (FaultConfig, FaultInjector, ReplicaCrash,
                                     ReplicaSupervisor, RetryPolicy,
                                     call_with_retry)
@@ -653,6 +654,7 @@ class StageRunner:
                          handle=None) -> None:
         spec = self.gen_stage
         name = f"rollout-{widx}"
+        bind_instance(name)
         rng = np.random.default_rng(1234 + widx)
         fn = self._stage_fn(spec)
         bs = spec.batch_size or self.cfg.rollout_batch
@@ -701,19 +703,18 @@ class StageRunner:
                 if self.stagger is not None:
                     if recv.staged_version() > recv.version and \
                             self.stagger.try_begin_update(widx):
-                        with self.log.span(name, "weight_sync"):
-                            recv.maybe_swap()
+                        recv.maybe_swap()
                         self.stagger.end_update(widx)
                 else:
                     recv.maybe_swap()          # delayed update: H2D only
                 floor = self.trainer_version - self.cfg.staleness
                 if recv.version < floor:       # staleness gate
-                    with self.log.span(name, "weight_sync"):
+                    with self.log.span(name, "staleness_wait"):
                         recv.wait_and_swap(floor, timeout=30.0)
             else:
                 # sync modes: strictly on-policy — wait for current weights
                 if recv.version < self.trainer_version:
-                    with self.log.span(name, "weight_sync"):
+                    with self.log.span(name, "staleness_wait"):
                         recv.wait_and_swap(self.trainer_version,
                                            timeout=30.0)
 
@@ -766,6 +767,7 @@ class StageRunner:
 
     def _transform_worker(self, spec: StageSpec, widx: int) -> None:
         name = f"{spec.name}-{widx}"
+        bind_instance(name)
         fn = self._stage_fn(spec)
         bs = spec.batch_size or self.cfg.train_micro_batch
         h_batch = self._h_batch.labels(stage=spec.name)
@@ -834,6 +836,7 @@ class StageRunner:
         watermark — exactly-once training across restarts."""
         spec = self.driver_stage
         name = "train-0"
+        bind_instance(name)
         cfg = self.cfg
         fn = self._stage_fn(spec)
         h_batch = self._h_batch.labels(stage=spec.name)
@@ -844,11 +847,10 @@ class StageRunner:
             got = 0
             while got < cfg.samples_per_step and not self._stop.is_set():
                 want = cfg.fetch_rows(got)
-                t0 = time.monotonic()
-                batch = self.tq.get(spec.name, want, consumer=name,
-                                    timeout=STEP_ROWS_TIMEOUT_S,
-                                    lease=use_lease)
-                self.log.record(name, "wait", t0, time.monotonic())
+                with self.log.span(name, "wait"):
+                    batch = self.tq.get(spec.name, want, consumer=name,
+                                        timeout=STEP_ROWS_TIMEOUT_S,
+                                        lease=use_lease)
                 if batch is None:
                     if self._stop.is_set():
                         return      # another stage failed; run() raises
@@ -1015,6 +1017,7 @@ class StageRunner:
         """Accumulating consumer without step semantics (e.g. the critic):
         streams micro-batches until the run stops, then drains."""
         name = f"{spec.name}-0"
+        bind_instance(name)
         fn = self._stage_fn(spec)
         bs = spec.batch_size or self.cfg.train_micro_batch
         sink = self.aux_metrics.setdefault(spec.name, [])
@@ -1177,6 +1180,7 @@ class StageRunner:
         trainer = threading.Thread(
             target=self._guard, args=(self._driver,),
             kwargs=dict(stage=self.driver_stage.name, worker=0), daemon=True)
+        prev_log = spans.set_log(self.log)   # the run's spans record here
         try:
             feeder.start()
             for w in self._threads:
@@ -1187,6 +1191,8 @@ class StageRunner:
                 super_mon.start()
             trainer.start()
             trainer.join()
+            # the last publish ends inside the run, spans and counts too
+            self.sender.flush()
             self._stop.set()
             self.tq.close()
             with self._pool_lock:
@@ -1218,6 +1224,7 @@ class StageRunner:
                     pass
             if sampler is not None:
                 sampler.stop()
+            spans.set_log(prev_log)
         if self._error is not None:
             raise RuntimeError(f"stage-graph run failed: {self._error}")
         if self._train_step < self.cfg.num_steps:

@@ -26,7 +26,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from repro.core.obs import get_registry
+from repro.core.obs import get_registry, span
 from repro.core.supervision.errors import WeightSyncTimeout
 
 
@@ -155,8 +155,12 @@ class BroadcastWeightChannel(WeightChannel):
 
 class WeightSender:
     """Training-cluster side. ``publish`` is non-blocking in async mode:
-    device→host offload + channel send happen on a background thread,
-    overlapping with the next training step (§4.2.3)."""
+    device→host offload + channel send happen on a background thread
+    (``weight-sender``), overlapping with the next training step
+    (§4.2.3). A publish first waits for the parameters to be computed
+    (``publish.wait``: the optimizer step, queued behind whatever the
+    device runs), then copies them to the host and offers them
+    (``publish.copy``)."""
 
     def __init__(self, channel: WeightChannel, mode: str = "async",
                  metrics=None):
@@ -168,20 +172,28 @@ class WeightSender:
         self._h_sync = m.histogram(
             "weight_sync_seconds",
             "weight publish (D2H + channel) / swap (H2D) durations")
+        self._h_wait = m.histogram(
+            "weight_publish_wait_seconds",
+            "part of a publish spent waiting for the parameters")
 
     def publish(self, params, version: int) -> None:
         def _send():
-            t0 = time.monotonic()
-            host = jax.tree.map(lambda a: np.asarray(a), params)
-            self.channel.offer(VersionedWeights(version, host))
-            self._h_sync.observe(time.monotonic() - t0, role="publish")
+            with span("publish.wait", version=version) as wait:
+                jax.block_until_ready(params)
+            with span("publish.copy", version=version) as copy:
+                host = jax.tree.map(lambda a: np.asarray(a), params)
+                self.channel.offer(VersionedWeights(version, host))
+            done = time.monotonic()
+            self._h_wait.observe(copy.start - wait.start)
+            self._h_sync.observe(done - wait.start, role="publish")
 
         if self.mode == "sync":
             _send()
         else:
             if self._pending is not None:
                 self._pending.join()
-            self._pending = threading.Thread(target=_send, daemon=True)
+            self._pending = threading.Thread(target=_send, daemon=True,
+                                             name="weight-sender")
             self._pending.start()
 
     def flush(self) -> None:
@@ -211,23 +223,17 @@ class WeightReceiver:
         self._h_sync = m.histogram(
             "weight_sync_seconds",
             "weight publish (D2H + channel) / swap (H2D) durations")
-        self._m_skipped = m.counter(
-            "weight_versions_skipped_total",
-            "published versions never loaded by a receiver (delayed "
-            "parameter update jumping straight to the newest)")
 
     def staged_version(self) -> int:
         vw = self.channel.peek()
         return vw.version if vw else self.version
 
     def _swap(self, vw: VersionedWeights) -> None:
-        t0 = time.monotonic()
-        self.params = self._to_device(vw.host_params)
-        skipped = vw.version - self.version - 1
-        if skipped > 0:
-            self._m_skipped.inc(skipped)
-        self.version = vw.version
-        self._h_sync.observe(time.monotonic() - t0, role="swap")
+        # times the dispatch of the host-to-device copy, not its end
+        with span("weight_swap", version=vw.version) as sw:
+            self.params = self._to_device(vw.host_params)
+            self.version = vw.version
+        self._h_sync.observe(time.monotonic() - sw.start, role="swap")
         if self.replica_id is not None and hasattr(self.channel, "ack"):
             self.channel.ack(self.replica_id, vw.version)
 

@@ -31,6 +31,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.core.obs import span
 from repro.engines.adapter import EngineRegistry, RLAdapter
 from repro.rl.advantage import grpo_advantages
 from repro.rl.reward import math_reward
@@ -107,21 +108,23 @@ class JaxRolloutEngine(RLAdapter):
         outs = sample_generate(params, self.cfg, flat, seed,
                                max_new_tokens=self.max_new_tokens,
                                temperature=self.temperature)
-        rows = []
-        for pi, p in enumerate(prompts):
-            gid = self._new_gid()
-            for m in range(G):
-                o = outs[pi * G + m]
-                rows.append(dict(
-                    prompt=p, response=o["tokens"], logprob=o["logprobs"],
-                    response_mask=o["response_mask"],
-                    response_ids=o["response_ids"],
-                    group=(gid, m, G), answer=p["answer"],
-                    token_len=int(o["response_mask"].sum())))
-        if emit is not None:
-            for r in rows:
-                emit(r)
-            return []
+        with span("generate.rows", n=len(outs)):
+            rows = []
+            for pi, p in enumerate(prompts):
+                gid = self._new_gid()
+                for m in range(G):
+                    o = outs[pi * G + m]
+                    rows.append(dict(
+                        prompt=p, response=o["tokens"],
+                        logprob=o["logprobs"],
+                        response_mask=o["response_mask"],
+                        response_ids=o["response_ids"],
+                        group=(gid, m, G), answer=p["answer"],
+                        token_len=int(o["response_mask"].sum())))
+            if emit is not None:
+                for r in rows:
+                    emit(r)
+                return []
         return rows
 
     def warm_up(self, params, prompts: List[dict], max_prompts: int) -> None:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.obs import span
 from repro.engines.adapter import EngineRegistry, RLAdapter
 from repro.rl.grpo import GRPOConfig, grpo_loss_fn
 from repro.rl.ppo import (PPOConfig, critic_forward, ppo_actor_loss_fn,
@@ -115,24 +116,32 @@ class _AccumulatingEngine(RLAdapter):
         raise NotImplementedError
 
     def _consume(self, batch: Dict[str, list]) -> dict:
-        jb = pack_rows(batch, self.seq_len)
-        grads, metrics = self._grad(jb)
-        if self._accum is None:
-            self._accum = grads
-        else:
-            self._accum = jax.tree.map(jnp.add, self._accum, grads)
-        self._accum_n += len(batch["response"])
-        self._accum_metrics.append(
-            {k: float(v) for k, v in metrics.items()})
+        n = len(batch["response"])
+        with span("update.pack", n=n):
+            jb = pack_rows(batch, self.seq_len)
+        with span("update.grad", n=n):
+            grads, metrics = self._grad(jb)
+            # accumulate before the read-back, so the device runs the
+            # adds right after the grad instead of idling for the host
+            with span("update.accumulate"):
+                if self._accum is None:
+                    self._accum = grads
+                else:
+                    self._accum = jax.tree.map(jnp.add, self._accum, grads)
+            self._accum_metrics.append(
+                {k: float(v) for k, v in metrics.items()})
+        self._accum_n += n
 
         if self._accum_n >= self.global_batch:
             n_micro = max(1, len(self._accum_metrics))
-            self.state, gnorm = _apply(self.state, self._accum,
-                                       float(n_micro), self.opt_cfg)
+            with span("update.optimizer"):
+                self.state, gnorm = _apply(self.state, self._accum,
+                                           float(n_micro), self.opt_cfg)
+                gnorm = float(gnorm)
             self.version += 1
             out = {k: float(np.mean([m[k] for m in self._accum_metrics]))
                    for k in self._accum_metrics[0]}
-            out["grad_norm"] = float(gnorm)
+            out["grad_norm"] = gnorm
             if "reward" in batch:
                 out["mean_reward"] = float(np.mean(batch["reward"]))
             self._accum, self._accum_n = None, 0

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.obs import span
 from repro.data.tokenizer import ByteTokenizer
 from repro.models import decode_step, init_cache
 
@@ -84,32 +85,35 @@ def generate(params, cfg, prompts: List[np.ndarray], rng_seed: int, *,
     (continuous-batching engines do the same bucketing)."""
     tok = ByteTokenizer()
     n_real = len(prompts)
-    prompts = list(prompts)
-    if bucket:
-        target_b, pad_len = generate_bucket(
-            n_real, max(len(p) for p in prompts))
-        prompts += [prompts[-1]] * (target_b - n_real)
-        toks, mask = tok.pad_batch(prompts, length=pad_len)
-    else:
-        toks, mask = tok.pad_batch(prompts)
-    lens = np.asarray([len(p) for p in prompts], np.int32)
-    out_toks, out_lps, resp_mask = _generate_jit(
-        params, cfg, jnp.asarray(toks), jnp.asarray(lens),
-        jax.random.PRNGKey(rng_seed), max_new=max_new_tokens,
-        temperature=temperature)
-    out_toks = np.asarray(out_toks)
-    out_lps = np.asarray(out_lps)
-    resp_mask = np.asarray(resp_mask)
+    with span("generate.prepare", n=n_real):
+        prompts = list(prompts)
+        if bucket:
+            target_b, pad_len = generate_bucket(
+                n_real, max(len(p) for p in prompts))
+            prompts += [prompts[-1]] * (target_b - n_real)
+            toks, mask = tok.pad_batch(prompts, length=pad_len)
+        else:
+            toks, mask = tok.pad_batch(prompts)
+        lens = np.asarray([len(p) for p in prompts], np.int32)
+        out_toks, out_lps, resp_mask = _generate_jit(
+            params, cfg, jnp.asarray(toks), jnp.asarray(lens),
+            jax.random.PRNGKey(rng_seed), max_new=max_new_tokens,
+            temperature=temperature)
+    with span("generate.device"):
+        out_toks = np.asarray(out_toks)
+        out_lps = np.asarray(out_lps)
+        resp_mask = np.asarray(resp_mask)
 
-    rows = []
-    for i in range(n_real):
-        lp_len = int(lens[i])
-        resp = out_toks[i, lp_len:]
-        cut = np.where(resp == eos_id)[0]
-        n_resp = int(cut[0]) + 1 if len(cut) else len(resp)
-        m = resp_mask[i].copy()
-        m[lp_len + n_resp:] = 0.0
-        rows.append(dict(tokens=out_toks[i], logprobs=out_lps[i],
-                         response_mask=m, response_ids=resp[:n_resp],
-                         prompt_len=lp_len))
+    with span("generate.rows", n=n_real):
+        rows = []
+        for i in range(n_real):
+            lp_len = int(lens[i])
+            resp = out_toks[i, lp_len:]
+            cut = np.where(resp == eos_id)[0]
+            n_resp = int(cut[0]) + 1 if len(cut) else len(resp)
+            m = resp_mask[i].copy()
+            m[lp_len + n_resp:] = 0.0
+            rows.append(dict(tokens=out_toks[i], logprobs=out_lps[i],
+                             response_mask=m, response_ids=resp[:n_resp],
+                             prompt_len=lp_len))
     return rows
