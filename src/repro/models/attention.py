@@ -51,6 +51,25 @@ def sdpa(q, k, v, mask):
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
+def grouped_decode_sdpa(q, k, v, valid):
+    """One query token against an unrepeated GQA cache.
+
+    q: (B,1,H,hd); k/v: (B,S,KV,hd) with H a multiple of KV; valid: (B,S).
+    Each group of H // KV query heads attends its one KV head inside the
+    einsums, so the cache is read once and never repeated per query head.
+    """
+    B, _, nh, hd = q.shape
+    nkv = k.shape[2]
+    qg = q.reshape(B, nkv, nh // nkv, hd)
+    scores = jnp.einsum("bgrd,bkgd->bgrk", qg, k,
+                        preferred_element_type=jnp.float32) * (hd ** -0.5)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bgrk,bkgd->bgrd", w, v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype).reshape(B, 1, nh, hd)
+
+
 def causal_mask(sq, sk, q_offset=0, window=0):
     """(1,1,sq,sk) causal mask; ``window``>0 adds a sliding-window band."""
     qpos = jnp.arange(sq)[:, None] + q_offset
@@ -149,7 +168,6 @@ def attend_decode(p, x, layer_cache, pos, cfg, *, ring=False, write=True,
         valid = (kpos < n_filled) if ring else (kpos <= pos[:, None])
     else:
         valid = jnp.ones((B, S), bool)
-    mask = valid[:, None, None, :]  # (B,1,1,S)
 
     if mesh is not None:
         from repro.distributed.flash_decode import sharded_decode_attention
@@ -159,9 +177,8 @@ def attend_decode(p, x, layer_cache, pos, cfg, *, ring=False, write=True,
         from repro.kernels.decode_attention.ops import decode_attention
         out = decode_attention(q, k_cache.astype(cd), v_cache.astype(cd), valid)
     else:
-        kk = _repeat_kv(k_cache.astype(cd), nh // nkv)
-        vv = _repeat_kv(v_cache.astype(cd), nh // nkv)
-        out = sdpa(q, kk, vv, mask)
+        out = grouped_decode_sdpa(q, k_cache.astype(cd), v_cache.astype(cd),
+                                  valid)
 
     out = dense(p["wo"], out.reshape(B, 1, nh * hd), cd)
     return out, {"k": k_cache, "v": v_cache}
