@@ -2,9 +2,11 @@
 
 Model FLOPs follow the usual convention: a matrix multiplication of an
 (m, k) by a (k, n) operand is 2*m*k*n operations. ``P`` counts the
-parameters that enter a matrix multiplication per token (the attention
-and MLP projections and the output head); the embedding lookup is a
-gather and is not counted. Recomputed work and padding are not counted.
+parameters that enter a matrix multiplication per token and the
+attention's own products are counted apart; both counts belong to the
+model's family and are the functions ``matmul_params(m)`` and
+``attention_flops_fwd(m, length)`` of the cell's reference module.
+Recomputed work and padding are not counted.
 """
 from __future__ import annotations
 
@@ -27,33 +29,19 @@ def peaks(device_kind: str, path: Path = PEAKS_FILE) -> dict:
     return table[device_kind]
 
 
-def matmul_params(m: dict) -> int:
-    """Parameters used in matrix multiplications per token of a dense
-    decoder ``m`` (the configuration file's ``model`` sizes)."""
-    d, hd = m["d_model"], m["head_dim"]
-    attn = d * hd * (2 * m["num_heads"] + 2 * m["num_kv_heads"])
-    n_mats = 3 if m.get("activation", "silu") == "silu" else 2
-    mlp = n_mats * d * m["d_ff"]
-    return m["num_layers"] * (attn + mlp) + d * m["vocab_size"]
-
-
-def attention_flops_fwd(m: dict, length: int) -> int:
-    """Score and value products of causal attention over one sequence of
-    ``length`` tokens, forward only: position t attends to t + 1 keys."""
-    pairs = length * (length + 1) // 2
-    return 4 * pairs * m["num_heads"] * m["head_dim"] * m["num_layers"]
-
-
-def rollout_flops(m: dict, length: int) -> int:
+def rollout_flops(counts, m: dict, length: int) -> int:
     """Forward operations to feed and generate one sequence of
-    ``length`` tokens (prompt plus response) through the decoder."""
-    return 2 * matmul_params(m) * length + attention_flops_fwd(m, length)
+    ``length`` tokens (prompt plus response) through the model of sizes
+    ``m``, by the family's ``counts`` (the cell's reference module)."""
+    return (2 * counts.matmul_params(m) * length
+            + counts.attention_flops_fwd(m, length))
 
 
-def train_flops(m: dict, length: int) -> int:
+def train_flops(counts, m: dict, length: int) -> int:
     """Forward and backward operations of the actor update on one
     sequence of ``length`` trained tokens (backward is twice forward)."""
-    return 6 * matmul_params(m) * length + 3 * attention_flops_fwd(m, length)
+    return (6 * counts.matmul_params(m) * length
+            + 3 * counts.attention_flops_fwd(m, length))
 
 
 def fused_rl_loss_cost(kind: str, n: int, v: int, dtype: str = "bf16"):

@@ -14,6 +14,11 @@ takes its two operands rounded to that dtype, and their gradients to
 float8 e5m2, each with one scale per tensor (amax scaling), and
 accumulates in float32: that is the control, the reference at the next
 precision below the configuration's bfloat16.
+
+Besides the forward pass and the update it declares what the harness
+needs to run its family: ``SOURCE_KEYS``, ``ARCH`` and ``defaults`` (how
+a configuration file becomes the program's ``ModelConfig``) and the
+counts of model FLOPs (``matmul_params``, ``attention_flops_fwd``).
 """
 from __future__ import annotations
 
@@ -25,6 +30,42 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 NORM_EPS = 1e-6
+
+# the family's config.json keys -> the program's ModelConfig fields
+SOURCE_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
+               "num_attention_heads": "num_heads",
+               "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
+               "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+               "rope_theta": "rope_theta", "hidden_act": "activation",
+               "tie_word_embeddings": "tie_embeddings"}
+
+# the ModelConfig fields that select the block this module computes
+ARCH = {"arch_type": "dense", "attention": "gqa"}
+
+
+def defaults(m: dict) -> dict:
+    """The fields a config.json of the family may leave out, at the values
+    the family then takes; q, k and v carry no bias unless the
+    configuration file's ``program`` says so."""
+    return {"head_dim": m["d_model"] // m["num_heads"], "rope_theta": 10000.0,
+            "tie_embeddings": False, "qkv_bias": False}
+
+
+def matmul_params(m: dict) -> int:
+    """Parameters used in matrix multiplications per token of a dense
+    decoder ``m`` (the configuration file's ``model`` sizes)."""
+    d, hd = m["d_model"], m["head_dim"]
+    attn = d * hd * (2 * m["num_heads"] + 2 * m["num_kv_heads"])
+    n_mats = 3 if m.get("activation", "silu") == "silu" else 2
+    mlp = n_mats * d * m["d_ff"]
+    return m["num_layers"] * (attn + mlp) + d * m["vocab_size"]
+
+
+def attention_flops_fwd(m: dict, length: int) -> int:
+    """Score and value products of causal attention over one sequence of
+    ``length`` tokens, forward only: position t attends to t + 1 keys."""
+    pairs = length * (length + 1) // 2
+    return 4 * pairs * m["num_heads"] * m["head_dim"] * m["num_layers"]
 
 
 def _round(x, dt):

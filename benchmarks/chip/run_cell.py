@@ -6,7 +6,11 @@
 The cell (``BENCHMARK.json`` ``workloads``) names a model configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<mix>.json``);
 its limits are ``limits/<workload>.json`` and each per-layer metric is
-read by ``metrics/<metric>.py``. Nothing here names a cell.
+read by ``metrics/<metric>.py``. The configuration names its plain
+reference (``references/<name>.py``), which holds all that belongs to
+the model's family: the key map to the program's ``ModelConfig``, the
+architecture it computes and the counts of model FLOPs. Nothing here
+names a cell or a family.
 
 A run drives the system's own entry point,
 ``Trainer(TrainerConfig(...), model_cfg=cfg, params=...).fit()``:
@@ -64,26 +68,53 @@ def _module(path: Path):
     return mod
 
 
-# a configuration file's keys (as the source's config.json names them)
-# -> the system's ModelConfig fields
-_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-         "num_attention_heads": "num_heads",
-         "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-         "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-         "rope_theta": "rope_theta", "hidden_act": "activation",
-         "tie_word_embeddings": "tie_embeddings"}
+def reference(conf: dict):
+    """The plain reference module a configuration file names."""
+    return _module(HERE / "references" / f"{conf['reference']}.py")
 
 
-def model_sizes(conf: dict) -> dict:
-    """The sizes a configuration file runs, under the system's names."""
+def model_sizes(conf: dict, ref) -> dict:
+    """The sizes a configuration file runs, as the program's ``ModelConfig``
+    fields, by the key map of its reference ``ref``: ``SOURCE_KEYS`` maps
+    a key of the file's ``config`` to a field, ``FIXED`` (where given)
+    holds keys with no field and the one value the reference computes. A
+    key in neither, a value other than the fixed one, or a null whose
+    field ``ref``'s ``defaults`` does not give is an error; the file's
+    ``program`` entries come last."""
     src = conf["config"]
-    m = {_KEYS[k]: v for k, v in src.items()}
-    m.setdefault("head_dim", m["d_model"] // m["num_heads"])
-    m.setdefault("rope_theta", 10000.0)
-    m.setdefault("tie_embeddings", False)
-    m["qkv_bias"] = False
-    m.update(conf.get("program", {}))
-    return m
+    fixed = getattr(ref, "FIXED", {})
+    name = repr(conf["reference"])
+    unknown = sorted(set(src) - set(ref.SOURCE_KEYS) - set(fixed))
+    if unknown:
+        raise KeyError(f"reference {name} maps no key {unknown}")
+    other = [k for k in fixed if k in src and src[k] != fixed[k]]
+    if other:
+        raise ValueError(f"reference {name} computes "
+                         + ", ".join(f"{k} {fixed[k]!r}, not {src[k]!r}"
+                                     for k in other))
+    m = {ref.SOURCE_KEYS[k]: v for k, v in src.items()
+         if k in ref.SOURCE_KEYS and v is not None}
+    given = ref.defaults(m)
+    unset = sorted(k for k, v in src.items() if v is None and k not in fixed
+                   and ref.SOURCE_KEYS[k] not in given)
+    if unset:
+        raise ValueError(f"reference {name} gives no value for the null "
+                         f"{unset}")
+    return {**given, **m, **conf.get("program", {})}
+
+
+def model_config(conf: dict, ref):
+    """The program's ``ModelConfig`` of the file's ``arch`` at the file's
+    sizes; an error unless it is the ``ARCH`` that ``ref`` computes."""
+    from repro.configs import get_config
+    cfg = dataclasses.replace(get_config(conf["arch"]),
+                              **model_sizes(conf, ref))
+    got = {k: getattr(cfg, k) for k in ref.ARCH}
+    if got != ref.ARCH:
+        raise ValueError(f"{conf['arch']} at these sizes is {got}; "
+                         f"reference {conf['reference']!r} computes "
+                         f"{ref.ARCH}")
+    return cfg
 
 
 @dataclasses.dataclass
@@ -96,9 +127,17 @@ class Cell:
     end_to_end: list
     per_layer: list
 
+    @functools.cached_property
+    def reference(self):
+        return reference(self.config)
+
     @property
     def model(self) -> dict:
-        return model_sizes(self.config)
+        return model_sizes(self.config, self.reference)
+
+    @property
+    def model_config(self):
+        return model_config(self.config, self.reference)
 
 
 def load_cell(workload: str, bench_file: Path = ROOT / "BENCHMARK.json"
@@ -298,22 +337,18 @@ class Warm:
 
 def setup(cell: Cell, seed: int) -> Warm:
     """Weights, the trainer and its warm steps (a first ``fit``)."""
-    import jax
-
     from repro.api import Trainer, TrainerConfig
-    from repro.configs import get_config
 
     import traffic as traffic_mod
     import weights
 
-    m, tr = cell.model, cell.traffic
+    cfg, tr = cell.model_config, cell.traffic
     key = key_seed(seed)
-    cfg = dataclasses.replace(get_config(cell.config["arch"]), **m)
     trainer = Trainer(TrainerConfig(**tr["trainer"], lr=tr["optimizer"]["lr"],
                                     num_steps=tr["warm_steps"], seed=key),
-                      model_cfg=cfg, params=weights.make(m, key))
+                      model_cfg=cfg, params=weights.make(cfg, key))
     trainer.dataset = traffic_mod.PromptStream(
-        seed, m["vocab_size"], *tr["prompt_len"])
+        seed, cfg.vocab_size, *tr["prompt_len"])
     probe = Probe(trainer, tr["trainer"]["seq_len"])
     b1 = tr["optimizer"]["betas"][0]
     first = {}
@@ -330,7 +365,7 @@ def setup(cell: Cell, seed: int) -> Warm:
     if len(steps) != tr["warm_steps"]:
         raise RuntimeError(f"{len(steps)} warm steps recorded, "
                            f"{tr['warm_steps']} run")
-    p0 = weights.make(m, key)
+    p0 = weights.make(cfg, key)
     change = diff_norms(trainer.train_engine.params, p0)
     del p0
     losses = [r["loss"] for r in sorted(res.metrics, key=lambda r: r["step"])]
@@ -350,6 +385,7 @@ class RunRecord:
     peaks: dict
     n_chips: int
     counters: dict    # the program's counters' change over the window
+    reference: object   # the cell's reference module: its FLOP counts
 
     @property
     def window_s(self) -> float:
@@ -455,7 +491,8 @@ def measure(cell: Cell, warm: Warm, seconds: float, trace: bool,
             trace=summary, model=cell.model,
             peaks=flops.peaks(dev.device_kind), n_chips=cell.chips,
             counters={k: v - marks["counters_a"][k]
-                      for k, v in marks["counters_b"].items()})
+                      for k, v in marks["counters_b"].items()},
+            reference=cell.reference)
         out["per_layer"] = {}
         for metric in cell.per_layer:
             got = _module(HERE / "metrics" / f"{metric['name']}.py").read(
@@ -528,7 +565,7 @@ def reference_side(cell: Cell, warm_steps: list, key: int, sample: list,
 
     import weights
 
-    ref = _module(HERE / "references" / f"{cell.config['reference']}.py")
+    ref = cell.reference
     m, tr = cell.model, cell.traffic
     S = tr["trainer"]["seq_len"]
     steps = []
@@ -547,7 +584,7 @@ def reference_side(cell: Cell, warm_steps: list, key: int, sample: list,
                 lps[i] = reference_lps(lp_fn, params, [row])[0]
 
     with jax.default_matmul_precision("highest"):
-        p0 = weights.make(m, key)
+        p0 = weights.make(cell.model_config, key)
         losses, grad, p_end = ref.follow(p0, m, steps, tr["optimizer"],
                                          tr["clip_eps"], quant=quant,
                                          rows_per_pass=rows_per_pass,
@@ -626,8 +663,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, log=print):
     per number compared, with its limit."""
     import check
 
+    import jax
+
     compile_log()
     warm = setup(cell, seed)
+    # where in the run the process's peak is reached: set-up or the window
+    log("peak_bytes_in_use after set-up: {}".format(
+        (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")))
     out = measure(cell, warm, seconds, trace, log=log)
     free(warm)
     log("setup_s {setup_s:.3f}, peak_hbm_gib {peak_hbm_gib:.4f}, "

@@ -71,10 +71,12 @@ def test_config_file_loads_and_runs_as_stated(conf):
                    if k != "vocab_size")
     for k, (published, run) in doc["reduced"].items():
         assert doc["config"][k] == run != published
-    m = run_cell.model_sizes(doc)
-    cfg = dataclasses.replace(get_config(doc["arch"]), **m)
-    assert cfg.arch_type == "dense" and cfg.attention == "gqa"
     assert (HERE / "references" / f"{doc['reference']}.py").is_file()
+    ref = run_cell.reference(doc)
+    m = run_cell.model_sizes(doc, ref)
+    cfg = dataclasses.replace(get_config(doc["arch"]), **m)
+    assert {k: getattr(cfg, k) for k in ref.ARCH} == ref.ARCH
+    assert run_cell.model_config(doc, ref) == cfg
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])

@@ -72,7 +72,8 @@ def test_idle_shares_partition_the_device_idle_share(recorded):
 
 def record(**kw):
     base = dict(window=(10.0, 20.0), n_steps=2, lengths=[24] * 8, spans=[],
-                trace=None, model={}, peaks={}, n_chips=1, counters={})
+                trace=None, model={}, peaks={}, n_chips=1, counters={},
+                reference=None)
     return run_cell.RunRecord(**{**base, **kw})
 
 

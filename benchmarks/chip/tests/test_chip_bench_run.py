@@ -1,5 +1,4 @@
 """The harness end to end at a tiny width on the CPU, and its refusals."""
-import dataclasses
 import json
 import math
 import shutil
@@ -11,6 +10,7 @@ import pytest
 
 import chip_bench_tiny as tiny  # puts the harness and the system on the path
 import run_cell  # noqa: E402
+from repro.configs import get_config  # noqa: E402
 
 ROOT = tiny.ROOT
 
@@ -73,19 +73,76 @@ def test_trained_length_stops_at_eos_and_seq_len():
     assert run_cell.trained_length(mask, 5) == 5
 
 
-def test_weights_have_the_program_layout():
+def _tiny_config(conf):
+    return run_cell.model_config(conf, run_cell.reference(conf))
+
+
+LAYOUTS = {
+    "tiny": lambda: _tiny_config(tiny.CONFIG),
+    "tiny_tied_mha": lambda: _tiny_config({**tiny.CONFIG, "config": {
+        **tiny.CONFIG["config"], "tie_word_embeddings": True,
+        "num_key_value_heads": 4}, "program": {}}),
+    **{arch: (lambda arch=arch: get_config(arch).reduced()) for arch in (
+        "qwen2_5_7b",          # dense, grouped-query attention
+        "minicpm3_4b",         # dense, latent attention (MLA)
+        "deepseek_v2_236b",    # experts (MoE) with MLA
+        "falcon_mamba_7b",     # state space (mamba)
+        "recurrentgemma_9b")}}  # hybrid: RG-LRU and local attention
+
+
+@pytest.mark.parametrize("name", LAYOUTS)
+def test_weights_have_the_program_layout(name):
     import jax
+    import numpy as np
 
     import weights
-    from repro.configs import get_config
     from repro.models import init_params
-    for conf in (tiny.CONFIG, {**tiny.CONFIG, "config": {
-            **tiny.CONFIG["config"], "tie_word_embeddings": True,
-            "num_key_value_heads": 4}, "program": {}}):
-        m = run_cell.model_sizes(conf)
-        cfg = dataclasses.replace(get_config(conf["arch"]), **m)
-        want = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-        got = weights.make(m, 7)
-        assert jax.tree.structure(want) == jax.tree.structure(got)
-        assert all(a.shape == b.shape and a.dtype == b.dtype for a, b in zip(
-            jax.tree.leaves(want), jax.tree.leaves(got)))
+    cfg = LAYOUTS[name]()
+    want = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    got = weights.make(cfg, 7)
+    # the maker takes its tree from the same eval_shape, so these two hold
+    # by construction; the draw below, and the pinned checksums of the tiny
+    # cell's weights, are what can fail
+    assert jax.tree.structure(want) == jax.tree.structure(got)
+    assert all(a.shape == b.shape and a.dtype == b.dtype for a, b in zip(
+        jax.tree.leaves(want), jax.tree.leaves(got)))
+    for path, leaf in jax.tree_util.tree_flatten_with_path(got)[0]:
+        centre = 1.0 if getattr(path[-1], "key", None) == "scale" else 0.0
+        # a leaf's mean of n draws of N(centre, 0.02^2), within 5 sigma
+        assert abs(float(np.mean(leaf)) - centre) < 5 * 0.02 / math.sqrt(
+            leaf.size), path
+
+
+# sha256 (first 16 hex digits) of each leaf's bytes of the tiny cell's
+# weights for key seed 1234567, as the maker gave them when it built the
+# dense tree by hand: drawing from the program's tree must not move a bit
+PINNED = {
+    "['blocks']['attn']['wk']['b']": "5405520b6d26f478",
+    "['blocks']['attn']['wk']['w']": "5e37a46340d25c0f",
+    "['blocks']['attn']['wo']['w']": "db99aabbc1e07609",
+    "['blocks']['attn']['wq']['b']": "2dec6ca6c86e7521",
+    "['blocks']['attn']['wq']['w']": "0d18fe1001df0d68",
+    "['blocks']['attn']['wv']['b']": "ad3293e3f8000111",
+    "['blocks']['attn']['wv']['w']": "2f5e96935c9b283f",
+    "['blocks']['ffn']['down']['w']": "328aa6d5f3c47623",
+    "['blocks']['ffn']['gate']['w']": "a3a7fdd31d3a087b",
+    "['blocks']['ffn']['up']['w']": "5a4cba46b034abbc",
+    "['blocks']['ln1']['scale']": "f696d19a2f121d8e",
+    "['blocks']['ln2']['scale']": "4e5a67131be651db",
+    "['embed']['table']": "4a97e65f0972978d",
+    "['final_norm']['scale']": "8d3daaacbf88f59a",
+    "['lm_head']['w']": "8a6b2817a0cafe72"}
+
+
+def test_tiny_cell_weights_match_their_pinned_checksums():
+    import hashlib
+
+    import jax
+    import numpy as np
+
+    import weights
+    got = weights.make(_tiny_config(tiny.CONFIG), 1234567)
+    assert {jax.tree_util.keystr(path): hashlib.sha256(
+        np.asarray(leaf).tobytes()).hexdigest()[:16]
+        for path, leaf in jax.tree_util.tree_flatten_with_path(got)[0]
+    } == PINNED

@@ -22,6 +22,7 @@ import trace_reduce  # noqa: E402
 DATA = HERE / "testdata"
 XPLANE = DATA / "tiny_run.xplane.pb"
 RESULT = json.loads((DATA / "tiny_run.result.json").read_text())
+dense_decoder = run_cell.reference({"reference": "dense_decoder"})
 
 
 @pytest.fixture(scope="module")
@@ -62,11 +63,13 @@ def test_readers_on_the_recorded_trace(summary):
     rec = run_cell.RunRecord(
         window=(0.0, summary["window_s"]), n_steps=2, lengths=[24] * 16,
         spans=[], trace=summary, model=run_cell.model_sizes(
-            {"config": {"hidden_size": 64, "intermediate_size": 128,
+            {"reference": "dense_decoder",
+             "config": {"hidden_size": 64, "intermediate_size": 128,
                         "num_attention_heads": 4, "num_key_value_heads": 2,
                         "num_hidden_layers": 2, "vocab_size": 512},
-             "program": {"qkv_bias": True}}),
-        peaks=flops.peaks("TPU v5 lite"), n_chips=1, counters={})
+             "program": {"qkv_bias": True}}, dense_decoder),
+        peaks=flops.peaks("TPU v5 lite"), n_chips=1, counters={},
+        reference=dense_decoder)
     read = lambda name: run_cell._module(  # noqa: E731
         HERE / "metrics" / f"{name}.py").read(rec)
     roof = read("fused_rl_loss_roofline")
@@ -86,7 +89,7 @@ def test_weight_sync_reads_the_programs_histogram_change():
         counters={"weight_sync_seconds.publish": 0.5,
                   "weight_sync_count.publish": 2,
                   "weight_sync_seconds.swap": 0.25,
-                  "weight_sync_count.swap": 2})
+                  "weight_sync_count.swap": 2}, reference=None)
     got = run_cell._module(
         HERE / "metrics" / "weight_sync_s_per_step.py").read(rec)
     assert got == {"value": 0.375, "publish_s": 0.25, "swap_s": 0.125}
